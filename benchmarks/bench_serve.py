@@ -946,8 +946,8 @@ def cli() -> int:
                     help="fail if tokens/sec drops more than this fraction "
                          "below the committed baseline")
     ap.add_argument("--mesh", type=int, default=0,
-                    help="shard the KV pool over this many devices (forces "
-                         "a CPU fake pod when needed); the run is verified "
+                    help="shard the KV pool over this many devices (a CPU "
+                         "fake pod under JAX_PLATFORMS=cpu); the run is verified "
                          "token-identical against an unsharded engine")
     ap.add_argument("--tp", type=int, default=0,
                     help="tensor-parallel: shard the weights AND the KV pool "
@@ -995,6 +995,8 @@ def cli() -> int:
     # inside _build_engine, so this is early enough)
     from repro.launch.mesh import ensure_fake_pod
     ensure_fake_pod(mesh_n)
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
 
     if args.family:
         if mesh_n:

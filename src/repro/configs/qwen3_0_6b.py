@@ -1,7 +1,8 @@
 """qwen3-0.6b [dense] — 28L d_model=1024 16H (GQA kv=8) d_ff=3072 vocab=151936.
 
 qk_norm + GQA; head_dim=128 (q proj widens 1024 -> 2048).  The paper's own
-evaluation family (Qwen3).  [hf:Qwen/Qwen3-8B; hf]
+evaluation family (Qwen3).  Widths, rope_theta and tied embeddings as in
+the published config.json.  [hf:Qwen/Qwen3-0.6B; config.json]
 """
 from repro.configs.base import ModelConfig, register
 

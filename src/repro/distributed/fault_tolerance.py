@@ -19,6 +19,7 @@ import time
 from typing import Callable, List, Optional
 
 import jax
+from jax.sharding import AxisType
 
 
 @dataclasses.dataclass
@@ -62,7 +63,9 @@ def elastic_remesh(current_mesh, lost_hosts: int = 1):
             devices = jax.devices()[:n]
             if len(devices) < n:
                 raise ValueError("not enough devices")
-            return jax.make_mesh(sizes, axes, devices=devices)
+            return jax.make_mesh(sizes, axes,
+                                 axis_types=(AxisType.Auto,) * len(axes),
+                                 devices=devices)
         except ValueError:
             new_data -= 1
     raise RuntimeError("no viable mesh after failures")

@@ -55,17 +55,21 @@ def lora_plan_block_out() -> int:
 def _shrink_kernel(idx_ref, x_ref, a_ref, o_ref):
     t = pl.program_id(0)
     valid = idx_ref[t] >= 0
-    h = jnp.dot(x_ref[...].astype(jnp.float32),
+    h = jnp.dot(x_ref[0].astype(jnp.float32),
                 a_ref[0].astype(jnp.float32),
                 preferred_element_type=jnp.float32)
-    o_ref[...] = jnp.where(valid, h, 0.0)
+    o_ref[0] = jnp.where(valid, h, 0.0)
 
 
 def lora_shrink_kernel(x: jax.Array, a_slab: jax.Array, idx: jax.Array,
                        interpret: bool = False) -> jax.Array:
     """x (T, d); a_slab (S, d, R); idx (T,) int32 slot per row, -1 = no
     adapter -> (T, R) f32.  Each grid step DMAs one row's adapter tile
-    ``A[idx[t]]`` (rows with idx < 0 read slot 0 and mask to zero)."""
+    ``A[idx[t]]`` (rows with idx < 0 read slot 0 and mask to zero).
+
+    Rows ride as ``(T, 1, d)`` so a one-row block's last two dims equal the
+    array's, which Mosaic accepts at any T; a ``(1, d)`` block of ``(T, d)``
+    breaks its (8, 128) tiling rule."""
     t, d = x.shape
     _, d2, r = a_slab.shape
     assert d == d2, f"x feature dim {d} != slab {d2}"
@@ -73,26 +77,27 @@ def lora_shrink_kernel(x: jax.Array, a_slab: jax.Array, idx: jax.Array,
         num_scalar_prefetch=1,      # idx
         grid=(t,),
         in_specs=[
-            pl.BlockSpec((1, d), lambda t, idx: (t, 0)),
+            pl.BlockSpec((1, 1, d), lambda t, idx: (t, 0, 0)),
             pl.BlockSpec((1, d, r),
                          lambda t, idx: (jnp.maximum(idx[t], 0), 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, r), lambda t, idx: (t, 0)),
+        out_specs=pl.BlockSpec((1, 1, r), lambda t, idx: (t, 0, 0)),
     )
-    return pl.pallas_call(
+    h = pl.pallas_call(
         _shrink_kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((t, r), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((t, 1, r), jnp.float32),
         interpret=interpret,
-    )(idx.astype(jnp.int32), x, a_slab)
+    )(idx.astype(jnp.int32), x.reshape(t, 1, d), a_slab)
+    return h.reshape(t, r)
 
 
 def _expand_kernel(idx_ref, h_ref, b_ref, o_ref):
     t = pl.program_id(0)
     valid = idx_ref[t] >= 0
-    y = jnp.dot(h_ref[...], b_ref[0].astype(jnp.float32),
+    y = jnp.dot(h_ref[0], b_ref[0].astype(jnp.float32),
                 preferred_element_type=jnp.float32)
-    o_ref[...] = jnp.where(valid, y, 0.0).astype(o_ref.dtype)
+    o_ref[0] = jnp.where(valid, y, 0.0).astype(o_ref.dtype)
 
 
 def lora_expand_kernel(h: jax.Array, b_slab: jax.Array, idx: jax.Array,
@@ -100,7 +105,8 @@ def lora_expand_kernel(h: jax.Array, b_slab: jax.Array, idx: jax.Array,
                        interpret: bool = False) -> jax.Array:
     """h (T, R) f32; b_slab (S, R, O); idx (T,) int32 -> (T, O) out_dtype.
     The grid tiles the output features by ``block_out`` so one step's
-    weight tile is ``(R, block_out)`` regardless of projection width."""
+    weight tile is ``(R, block_out)`` regardless of projection width.
+    Rows ride as ``(T, 1, .)`` for the same tiling reason as the shrink."""
     t, r = h.shape
     _, r2, o = b_slab.shape
     assert r == r2, f"h rank {r} != slab {r2}"
@@ -113,16 +119,16 @@ def lora_expand_kernel(h: jax.Array, b_slab: jax.Array, idx: jax.Array,
         num_scalar_prefetch=1,      # idx
         grid=(t, steps),
         in_specs=[
-            pl.BlockSpec((1, r), lambda t, j, idx: (t, 0)),
+            pl.BlockSpec((1, 1, r), lambda t, j, idx: (t, 0, 0)),
             pl.BlockSpec((1, r, bo),
                          lambda t, j, idx: (jnp.maximum(idx[t], 0), 0, j)),
         ],
-        out_specs=pl.BlockSpec((1, bo), lambda t, j, idx: (t, j)),
+        out_specs=pl.BlockSpec((1, 1, bo), lambda t, j, idx: (t, 0, j)),
     )
     y = pl.pallas_call(
         _expand_kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((t, o + pad), out_dtype),
+        out_shape=jax.ShapeDtypeStruct((t, 1, o + pad), out_dtype),
         interpret=interpret,
-    )(idx.astype(jnp.int32), h, b_slab)
+    )(idx.astype(jnp.int32), h.reshape(t, 1, r), b_slab).reshape(t, o + pad)
     return y[:, :o] if pad else y

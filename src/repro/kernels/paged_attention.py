@@ -72,7 +72,7 @@ def _paged_kernel(tables_ref, lens_ref, qpos_ref, q_ref, *refs,
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     kv_len = lens_ref[b]          # scalar read from SMEM
-    qpos = qpos_ref[0]            # (R,) per-row causal bound, VMEM
+    qpos = qpos_ref[0]            # (R, 1) per-row causal bound, VMEM
 
     for p in range(p_f):
         page_no = j * p_f + p
@@ -89,7 +89,7 @@ def _paged_kernel(tables_ref, lens_ref, qpos_ref, q_ref, *refs,
                             preferred_element_type=jnp.float32) * scale
                 kpos = page_no * block_size + jax.lax.broadcasted_iota(
                     jnp.int32, s.shape, 1)
-                live = (kpos <= qpos[:, None]) & (kpos < kv_len)
+                live = (kpos <= qpos) & (kpos < kv_len)
                 s = jnp.where(live, s, NEG_INF)
                 # rows fully masked in this page contribute at m == NEG_INF;
                 # the first real score's alpha rescale annihilates them, and
@@ -147,7 +147,10 @@ def paged_attention_kernel(q: jax.Array, k_pages: jax.Array,
         num_scalar_prefetch=2,      # block_tables, kv_lens
         grid=(b, steps),
         in_specs=[
-            pl.BlockSpec((1, r), lambda b, j, tables, lens: (b, 0)),
+            # q_pos rides as (B, R, 1): a block's last two dims must equal
+            # the array's (or tile by (8, 128)), and (R, 1) is both whole and
+            # already column-shaped for the (R, bs) score mask
+            pl.BlockSpec((1, r, 1), lambda b, j, tables, lens: (b, 0, 0)),
             pl.BlockSpec((1, kv_heads, r, hd),
                          lambda b, j, tables, lens: (b, 0, 0, 0)),
         ] + page_spec + page_spec,
@@ -167,5 +170,5 @@ def paged_attention_kernel(q: jax.Array, k_pages: jax.Array,
         out_shape=jax.ShapeDtypeStruct((b, kv_heads, r, hd), q.dtype),
         interpret=interpret,
     )(block_tables.astype(jnp.int32), kv_lens.astype(jnp.int32),
-      q_pos.astype(jnp.int32), q,
+      q_pos.astype(jnp.int32).reshape(b, r, 1), q,
       *([k_pages] * p_f), *([v_pages] * p_f))

@@ -88,8 +88,10 @@ def main() -> int:
                          "shapes (faster boot; smoke/CI use)")
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import use_compile_cache
     from repro.launch.mesh import ensure_fake_pod
     ensure_fake_pod(args.mesh)
+    use_compile_cache()
     asyncio.run(serve(args))
     return 0
 

@@ -6,32 +6,38 @@ never touches jax device state.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     """16x16 single pod (256 chips) or 2x16x16 multi-pod (512 chips)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(shape))
 
 
 def make_local_mesh():
     """Degenerate 1x1 mesh over whatever devices exist (tests/examples)."""
     n = len(jax.devices())
-    return jax.make_mesh((n, 1), ("data", "model"))
+    return jax.make_mesh((n, 1), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)
 
 
 def ensure_fake_pod(n: int) -> None:
     """Ask XLA for an ``n``-device CPU fake pod by appending
     ``--xla_force_host_platform_device_count`` to XLA_FLAGS.
 
-    Only effective if the backend has not initialized yet (XLA reads the
-    flag at first device use) — call it before anything touches
-    ``jax.devices()``.  No-op when ``n <= 1`` or when XLA_FLAGS already
-    carries a forced count (an operator's explicit setting wins); on real
-    accelerators the flag only affects the CPU platform and is ignored."""
+    Acts only when ``JAX_PLATFORMS`` is explicitly ``cpu``: a run meant for
+    an accelerator must find its devices or fail, never quietly carry on
+    over fake CPU devices.  Only effective if the backend has not
+    initialized yet (XLA reads the flag at first device use) — call it
+    before anything touches ``jax.devices()``.  No-op when ``n <= 1`` or
+    when XLA_FLAGS already carries a forced count (an operator's explicit
+    setting wins)."""
     import os
 
+    if os.environ.get("JAX_PLATFORMS", "") != "cpu":
+        return
     flags = os.environ.get("XLA_FLAGS", "")
     if n > 1 and "xla_force_host_platform_device_count" not in flags:
         os.environ["XLA_FLAGS"] = (

@@ -11,11 +11,11 @@ layout (KV blocks for the shared attention, slab slots for the Mamba2
 backbone).
 
 ``--mesh N`` shards the KV block pool over N devices on the kv-heads axis
-(on a chipless host it forces an N-device CPU fake pod first); outputs are
-token-identical to the single-device run.  ``--tp N`` additionally shards
-the WEIGHTS over the same mesh using the partition rules Auto Distribution
-emits (~1/N param bytes per device; see docs/sharding.md and the
-REPRO_TP_REDUCE_SCATTER knob).  Prints per-run ServeMetrics;
+(under ``JAX_PLATFORMS=cpu`` it forces an N-device CPU fake pod first);
+outputs are token-identical to the single-device run.  ``--tp N``
+additionally shards the WEIGHTS over the same mesh using the partition rules
+Auto Distribution emits (~1/N param bytes per device; see docs/sharding.md
+and the REPRO_TP_REDUCE_SCATTER knob).  Prints per-run ServeMetrics;
 ``--metrics-out`` dumps them as JSON (the same shape bench_serve emits into
 BENCH_serve.json).
 """
@@ -28,6 +28,7 @@ import jax
 import numpy as np
 
 from repro.configs.base import get_config, reduced_config
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.mesh import ensure_fake_pod
 from repro.models import build_model
 from repro.serve.engine import Request, SamplingParams, ServeEngine
@@ -58,8 +59,8 @@ def main():
     ap.add_argument("--mesh", type=int, default=0,
                     help="shard the KV pool over this many devices on the "
                          "kv-heads axis (1 = explicit 1-device mesh; 0 = "
-                         "defer to REPRO_SERVE_MESH; forces a CPU fake pod "
-                         "when not enough devices exist)")
+                         "defer to REPRO_SERVE_MESH; a CPU fake pod under "
+                         "JAX_PLATFORMS=cpu)")
     ap.add_argument("--tp", type=int, default=0,
                     help="tensor-parallel: shard the weights AND the KV pool "
                          "over this many devices (implies --mesh N; 0 = "
@@ -72,6 +73,7 @@ def main():
 
     mesh_n = max(args.mesh, args.tp)
     ensure_fake_pod(mesh_n)
+    use_compile_cache()
     mesh = None          # 0: defer to the REPRO_SERVE_MESH knob
     if mesh_n >= 1:      # an explicit CLI width always beats the env knob
         from repro.launch.mesh import make_serve_mesh

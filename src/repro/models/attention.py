@@ -269,7 +269,6 @@ def attention_decode_block_paged(cfg: ModelConfig, p, x: jax.Array,
         v_pages = paged_scatter_token(v_pages, block_tables, seq_lens, v[:, 0])
         o = _paged_decode_attend(q, k_pages, v_pages, block_tables, seq_lens)
     else:
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
         from repro.distributed.param_sharding import serve_tp_reduce_scatter
         hs = P(None, None, "model", None)    # heads/kv axis of q, k, v, pages
@@ -286,11 +285,11 @@ def attention_decode_block_paged(cfg: ModelConfig, p, x: jax.Array,
                 o_l = jax.lax.all_gather(o_l, "model", axis=2, tiled=True)
             return o_l, kp_l, vp_l
 
-        o, k_pages, v_pages = shard_map(
+        o, k_pages, v_pages = jax.shard_map(
             body, mesh=mesh,
             in_specs=(hs, hs, hs, hs, hs, P(None, None), P(None)),
             out_specs=(hs if rs else P(None, None, None, None), hs, hs),
-            check_rep=False)(q, k, v, k_pages, v_pages, block_tables, seq_lens)
+            check_vma=False)(q, k, v, k_pages, v_pages, block_tables, seq_lens)
     b = x.shape[0]
     from repro.distributed.sharding import weight_use
     from repro.models import lora as lora_mod
@@ -344,7 +343,6 @@ def attention_prefill_chunk_block(cfg: ModelConfig, p, x: jax.Array,
         # then the head-split outputs are all-gathered (no cross-shard sums)
         # — except under reduce-scatter TP, where they stay head-sharded for
         # the row-parallel wo (one all-reduce on its partial sums instead)
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
         from repro.distributed.param_sharding import serve_tp_reduce_scatter
         hs = P(None, None, "model", None)
@@ -358,12 +356,12 @@ def attention_prefill_chunk_block(cfg: ModelConfig, p, x: jax.Array,
                 o_l = jax.lax.all_gather(o_l, "model", axis=2, tiled=True)
             return o_l, kp_l, vp_l
 
-        o, k_pages, v_pages = shard_map(
+        o, k_pages, v_pages = jax.shard_map(
             body, mesh=mesh,
             in_specs=(hs, hs, hs, hs, hs, P(None, None), P(None), P(None),
                       P(None)),
             out_specs=(hs if rs else P(None, None, None, None), hs, hs),
-            check_rep=False)(q, k, v, k_pages, v_pages, block_table, blk,
+            check_vma=False)(q, k, v, k_pages, v_pages, block_table, blk,
                              off, chunk_pos)
     from repro.distributed.sharding import weight_use
     from repro.models import lora as lora_mod

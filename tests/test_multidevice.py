@@ -24,7 +24,8 @@ def _run(script: str, n_devices: int = 8) -> dict:
             + textwrap.dedent(script))
     r = subprocess.run([sys.executable, "-c", code], capture_output=True,
                        text=True, timeout=600,
-                       env={"PYTHONPATH": str(ROOT / "src"), "PATH": "/usr/bin:/bin"})
+                       env={"PYTHONPATH": str(ROOT / "src"), "PATH": "/usr/bin:/bin",
+                            "JAX_PLATFORMS": "cpu"})
     assert r.returncode == 0, r.stderr[-3000:]
     return json.loads(r.stdout.strip().splitlines()[-1])
 
@@ -33,7 +34,8 @@ def test_elastic_remesh_shrinks_data_axis():
     out = _run("""
     import json, jax
     from repro.distributed.fault_tolerance import elastic_remesh
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = jax.make_mesh((4, 2), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
     m2 = elastic_remesh(mesh, lost_hosts=1)
     print(json.dumps({"shape": dict(m2.shape), "n": int(m2.devices.size)}))
     """)
@@ -51,7 +53,8 @@ def test_sharded_train_step_runs_on_8_devices():
     from repro.models import build_model
 
     cfg = reduced_config(get_config("qwen3-0.6b"))
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = jax.make_mesh((4, 2), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
     fns = build_model(cfg)
     step, opt = make_train_step(cfg, remat=False)
     with mesh:
@@ -85,7 +88,8 @@ def test_dryrun_cell_runner_small_mesh():
     # shrink the production mesh for the test host
     mesh_mod.make_production_mesh = lambda multi_pod=False: jax.make_mesh(
         (2, 2, 2) if multi_pod else (4, 2),
-        ("pod", "data", "model") if multi_pod else ("data", "model"))
+        ("pod", "data", "model") if multi_pod else ("data", "model"),
+        axis_types=(jax.sharding.AxisType.Auto,) * (3 if multi_pod else 2))
     import repro.launch.dryrun as dr
     from pathlib import Path
     import tempfile
