@@ -1,0 +1,6 @@
+"""Share of the traced window in which no operation ran on the device."""
+from bench.measures import idle_share
+
+
+def read(run):
+    return idle_share(run)
