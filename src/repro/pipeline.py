@@ -38,6 +38,7 @@ import tempfile
 import time
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
+from repro import tracing
 from repro.core.buffer_schedule import (liveness_from_term, naive_peak,
                                         plan_greedy, plan_optimal)
 from repro.core.codegen import KernelPlan, compile_term, kernel_plan
@@ -301,16 +302,21 @@ def cache_key(term: Term, target: CompileTarget,
 # ---------------------------------------------------------------------------
 
 class _Timer:
+    """Times one pass into ``report.pass_times``, and records it as the
+    tracer's span ``pipeline.<pass>``."""
+
     def __init__(self, report: CompileReport, name: str):
         self.report, self.name = report, name
+        self.span = tracing.span(f"pipeline.{name}")
 
     def __enter__(self):
+        self.span.__enter__()
         self.t0 = time.monotonic()
         return self
 
     def __exit__(self, *exc):
         self.report.pass_times[self.name] = time.monotonic() - self.t0
-        return False
+        return self.span.__exit__(*exc)
 
 
 def _extract(backend: str, eg: EGraph, root: int):

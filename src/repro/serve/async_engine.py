@@ -33,9 +33,11 @@ from __future__ import annotations
 import asyncio
 import itertools
 import threading
+import time
 from collections import deque
 from typing import AsyncIterator, Dict, List, Optional, Sequence, Tuple
 
+from repro import tracing
 from repro.perf import perf
 from repro.serve.engine import GREEDY, Request, SamplingParams, ServeEngine
 
@@ -150,7 +152,7 @@ class AsyncServeEngine:
         rid = next(self._rids)
         req = Request(rid=rid, prompt=list(prompt), max_new=max_new,
                       sampling=sampling, deadline_ms=deadline_ms,
-                      adapter_id=adapter_id)
+                      adapter_id=adapter_id, t_received=time.monotonic())
         stream = TokenStream(rid, req, asyncio.Queue())
         if self._stopping or not self.running:
             # called on the event loop thread: enqueue the terminal directly
@@ -247,14 +249,16 @@ class AsyncServeEngine:
         # live streams and racing submits always get a terminal event.
         try:
             while True:
-                self._drain_inbox()
+                with tracing.span("stepper.drain"):
+                    self._drain_inbox()
                 if self._stopping:
                     break
                 worked = self.engine.step_guarded()
                 if not worked:
                     # drained: park until a submit/cancel/stop wakes us (the
                     # timeout covers a race where work arrived after step())
-                    self._wake.wait(self.idle_s)
+                    with tracing.span("stepper.idle"):
+                        self._wake.wait(self.idle_s)
                     self._wake.clear()
         finally:
             # terminate whatever was still in flight so consumers unblock —

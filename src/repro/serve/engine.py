@@ -82,6 +82,7 @@ from repro.core.tensor_ir import inp, matmul, unary
 from repro.distributed import param_sharding
 from repro.models import build_model
 from repro.models import attention as attn_lib
+from repro import tracing
 from repro.perf import perf
 from repro.pipeline import CompileOptions, Compiler, default_compiler
 from repro.kernels import lora as lora_kernels
@@ -145,8 +146,13 @@ class Request:
     # absolute monotonic cutoff into _deadline_at at submit time.
     deadline_ms: Optional[float] = None
     _deadline_at: float = 0.0
-    # timing (monotonic seconds; filled in by the engine)
+    # timing (monotonic seconds).  t_received: the front-end took the
+    # request (the async inbox; equal to t_submit when submitted directly);
+    # t_submit: the engine took it; t_prefill0: its first prefill dispatch,
+    # kept across preemption; t_first: its first token; t_done: it ended
+    t_received: float = 0.0
     t_submit: float = 0.0
+    t_prefill0: float = 0.0
     t_first: float = 0.0
     t_done: float = 0.0
     # streaming hooks (the async front-end wires these; both run inside the
@@ -176,6 +182,23 @@ class Request:
         if self.done:
             return "length"
         return ""
+
+
+_REQUEST_SPANS = ("request.inbox", "request.queue", "request.prefill",
+                  "request.decode")
+
+
+def _trace_request(req: Request) -> None:
+    """A request's spans, a view of its stamps (received, submit,
+    prefill0, first, done): each span between two stamps that were
+    taken."""
+    if not tracing.enabled():
+        return
+    ns = [round(t * 1e9) for t in (req.t_received, req.t_submit,
+                                   req.t_prefill0, req.t_first, req.t_done)]
+    for name, t0, t1 in zip(_REQUEST_SPANS, ns, ns[1:]):
+        if t0 and t1:
+            tracing.record(name, t0, t1, rid=req.rid)
 
 
 @dataclasses.dataclass
@@ -470,18 +493,21 @@ class ServeEngine:
         self.compile_report = None
         self.kernel_plan = None
         if plan_kernels and self.has_attention:
-            compiler = compiler or default_compiler()
-            hd = cfg.resolved_head_dim
-            span = self.max_blocks_per_seq * block_size
-            opts = CompileOptions(extraction="greedy", schedule_iterations=10)
-            dec = compiler.compile(paged_decode_attention_term(span, hd),
-                                   options=opts)
-            pre = compiler.compile(
-                chunked_prefill_attention_term(self.prefill_chunk_tokens,
-                                               span, hd), options=opts)
-            self.compile_reports = {"decode": dec.report, "prefill": pre.report}
-            self.compile_report = dec.report
-            self.kernel_plan = dec.report.kernel_plan
+            with tracing.span("engine.kernel_plan"):
+                compiler = compiler or default_compiler()
+                hd = cfg.resolved_head_dim
+                span = self.max_blocks_per_seq * block_size
+                opts = CompileOptions(extraction="greedy",
+                                      schedule_iterations=10)
+                dec = compiler.compile(paged_decode_attention_term(span, hd),
+                                       options=opts)
+                pre = compiler.compile(
+                    chunked_prefill_attention_term(self.prefill_chunk_tokens,
+                                                   span, hd), options=opts)
+                self.compile_reports = {"decode": dec.report,
+                                        "prefill": pre.report}
+                self.compile_report = dec.report
+                self.kernel_plan = dec.report.kernel_plan
 
         # the compiler's kv tile for the *decode* shape sets how many pages
         # the paged-attention kernel streams per grid step; the jit wrappers
@@ -595,6 +621,8 @@ class ServeEngine:
         ``deadline_ms`` or the REPRO_SERVE_DEADLINE_MS default) is stamped
         here; the step loop's reaper enforces it."""
         req.t_submit = time.monotonic()
+        if not req.t_received:
+            req.t_received = req.t_submit
         self._submitted += 1
         if self.max_queue and len(self.queue) >= self.max_queue:
             req.shed = True
@@ -975,6 +1003,7 @@ class ServeEngine:
         self._tenant_finished[t] = self._tenant_finished.get(t, 0) + 1
         a.req.done = True
         a.req.t_done = time.monotonic() if now is None else now
+        _trace_request(a.req)
         a.table.release_to(self.store)
         self.pool.release(a.reserved_left)
         a.reserved_left = 0
@@ -1000,6 +1029,7 @@ class ServeEngine:
         req.cancelled = True
         req.done = True
         req.t_done = time.monotonic()
+        _trace_request(req)
         self.cancelled.append(req)
         if req.on_finish is not None:
             req.on_finish(req)
@@ -1059,6 +1089,7 @@ class ServeEngine:
         req.expired = True
         req.done = True
         req.t_done = time.monotonic()
+        _trace_request(req)
         self.expired.append(req)
         if req.on_finish is not None:
             req.on_finish(req)
@@ -1072,6 +1103,7 @@ class ServeEngine:
         req.error = msg
         req.done = True
         req.t_done = time.monotonic()
+        _trace_request(req)
         self.errored.append(req)
         if req.on_finish is not None:
             try:
@@ -1258,44 +1290,50 @@ class ServeEngine:
     def _prefill_chunk_for(self, a: _Active) -> bool:
         req, c = a.req, self.prefill_chunk_tokens
         plen = len(req.prompt)
-        if a.next_prefill == 0 and not a.table.blocks:
-            self._adopt_prefix(a)
-        start = a.next_prefill
-        # realign to the canonical chunk grid: an adopted (or restored)
-        # prefix can leave ``start`` mid-chunk, and letting every offset
-        # produce its own attended-span value would retrace the jitted
-        # prefill per offset — the first chunk is shortened to the next grid
-        # point instead, so m_used stays in the same small set every request
-        # visits (the write limit masks the chunk's unused tail positions)
-        end = min(plen, start + c, (start // c + 1) * c)
-        if not self._grow(a, end):
-            return True  # preempted ourselves; the step still did work
-        if not self._make_writable(a, start, end):
-            return True
-        chunk = req.prompt[start:end] + [0] * (c - (end - start))
-        batch = {
-            "tokens": jnp.asarray([chunk], jnp.int32),
-            "block_table": jnp.asarray(
-                [a.table.padded(self.max_blocks_per_seq)], jnp.int32),
-            "start": jnp.int32(start),
-            "prompt_len": jnp.int32(end),
-        }
-        if a.state is not None:
-            # traced slot index: one jit per cache shape, not per slot
-            batch["state_slot"] = jnp.int32(a.state.idx)
-        lora = self._lora_descriptor(
-            np.asarray([a.req._adapter_slot], np.int32))
-        if lora is not None:
-            batch["lora"] = lora
-        # attend only over blocks written so far, not the full table capacity
-        # (attention-free prefill ignores the span — pin the static arg to 0
-        # so distinct chunk counts don't retrace the jit)
-        m_used = min(blocks_for_tokens(end, self.block_size),
-                     self.max_blocks_per_seq) if self.has_attention else 0
-        if self.faults is not None:
-            self.faults.check("step")
-        self.cache, logits = self._prefill_fn(self.params, self.cache, batch,
-                                              m_used=m_used)
+        with tracing.span("prefill.prepare"):
+            if a.next_prefill == 0 and not a.table.blocks:
+                self._adopt_prefix(a)
+            start = a.next_prefill
+            # realign to the canonical chunk grid: an adopted (or restored)
+            # prefix can leave ``start`` mid-chunk, and letting every offset
+            # produce its own attended-span value would retrace the jitted
+            # prefill per offset — the first chunk is shortened to the next
+            # grid point instead, so m_used stays in the same small set every
+            # request visits (the write limit masks the chunk's unused tail
+            # positions)
+            end = min(plen, start + c, (start // c + 1) * c)
+            if not self._grow(a, end):
+                return True  # preempted ourselves; the step still did work
+            if not self._make_writable(a, start, end):
+                return True
+            chunk = req.prompt[start:end] + [0] * (c - (end - start))
+            batch = {
+                "tokens": jnp.asarray([chunk], jnp.int32),
+                "block_table": jnp.asarray(
+                    [a.table.padded(self.max_blocks_per_seq)], jnp.int32),
+                "start": jnp.int32(start),
+                "prompt_len": jnp.int32(end),
+            }
+            if a.state is not None:
+                # traced slot index: one jit per cache shape, not per slot
+                batch["state_slot"] = jnp.int32(a.state.idx)
+            lora = self._lora_descriptor(
+                np.asarray([a.req._adapter_slot], np.int32))
+            if lora is not None:
+                batch["lora"] = lora
+            # attend only over blocks written so far, not the full table
+            # capacity (attention-free prefill ignores the span — pin the
+            # static arg to 0 so distinct chunk counts don't retrace the jit)
+            m_used = min(blocks_for_tokens(end, self.block_size),
+                         self.max_blocks_per_seq) if self.has_attention else 0
+            if self.faults is not None:
+                self.faults.check("step")
+        if not req.t_prefill0:
+            req.t_prefill0 = time.monotonic()
+        with tracing.span("prefill.dispatch", rid=req.rid, start=start,
+                          end=end, last=end >= plen):
+            self.cache, logits = self._prefill_fn(self.params, self.cache,
+                                                  batch, m_used=m_used)
         a.next_prefill = end
         self._prefill_tokens += end - start
         if a.prefill_done:
@@ -1306,8 +1344,13 @@ class ServeEngine:
                 req.prompt,
                 a.table.blocks[:blocks_for_tokens(plen, self.block_size)],
                 namespace=req.adapter_id)
-            row = np.asarray(logits[0, plen - 1 - start])
-            first = self._sample(row, req.sampling, 0)
+            row = logits[0, plen - 1 - start]
+            with tracing.span("prefill.device_wait"):
+                row.block_until_ready()
+            with tracing.span("prefill.logits_copy"):
+                row = np.asarray(row)
+            with tracing.span("prefill.sample"):
+                first = self._sample(row, req.sampling, 0)
             req.out.append(first)
             self._tenant_count(req)
             req.t_first = time.monotonic()
@@ -1320,63 +1363,81 @@ class ServeEngine:
     # -- decode ------------------------------------------------------------
     def _decode_step(self) -> bool:
         """One batched decode step for every live (prefill-complete) slot."""
-        live = [s for s in self.slots if s is not None and s.prefill_done]
-        # make sure every live row can write its next KV entry — growing the
-        # table AND privatizing a shared write target; under optimistic
-        # admission either can preempt (an earlier row's growth may evict a
-        # later row — or the row itself, when it is the youngest)
-        for a in live:
-            if a in self.slots:
-                with self._blame(a.req.rid):
-                    if self._grow(a, a.pos + 1):
-                        self._make_writable(a, a.pos, a.pos + 1)
-        live = [a for a in live if a in self.slots]
-        if not live:
-            return False
+        with tracing.span("decode.prepare"):
+            live = [s for s in self.slots
+                    if s is not None and s.prefill_done]
+            # make sure every live row can write its next KV entry — growing
+            # the table AND privatizing a shared write target; under
+            # optimistic admission either can preempt (an earlier row's
+            # growth may evict a later row — or the row itself, when it is
+            # the youngest)
+            for a in live:
+                if a in self.slots:
+                    with self._blame(a.req.rid):
+                        if self._grow(a, a.pos + 1):
+                            self._make_writable(a, a.pos, a.pos + 1)
+            live = [a for a in live if a in self.slots]
+            if not live:
+                return False
 
-        m = self.max_blocks_per_seq
-        tok = np.zeros((self.max_batch, 1), np.int32)
-        tables = np.zeros((self.max_batch, m), np.int32)
-        lens = np.zeros((self.max_batch,), np.int32)
-        state_slots = np.zeros((self.max_batch,), np.int32)  # 0 = null slot
-        adapter_ids = np.full((self.max_batch,), -1, np.int32)
-        rows = []
-        for a in live:
-            i = self.slots.index(a)
-            rows.append((i, a))
-            tok[i, 0] = a.req.out[-1]
-            tables[i] = a.table.padded(m)
-            lens[i] = a.pos
-            if a.state is not None:
-                state_slots[i] = a.state.idx
-            adapter_ids[i] = a.req._adapter_slot
-        batch = {"token": jnp.asarray(tok),
-                 "block_tables": jnp.asarray(tables),
-                 "seq_lens": jnp.asarray(lens)}
-        if self.has_state:
-            batch["state_slots"] = jnp.asarray(state_slots)
-        lora = self._lora_descriptor(adapter_ids)
-        if lora is not None:
-            batch["lora"] = lora
-        # the batched dispatch has no single owner: a crash here blames no
-        # rid and _on_step_crash falls back to the youngest live request
-        if self.faults is not None:
-            self.faults.check("step")
-        self.cache, logits = self._decode_fn(self.params, self.cache, batch)
-        logits_np = np.asarray(logits)
+            m = self.max_blocks_per_seq
+            tok = np.zeros((self.max_batch, 1), np.int32)
+            tables = np.zeros((self.max_batch, m), np.int32)
+            lens = np.zeros((self.max_batch,), np.int32)
+            state_slots = np.zeros((self.max_batch,), np.int32)  # 0 = null
+            adapter_ids = np.full((self.max_batch,), -1, np.int32)
+            rows = []
+            for a in live:
+                i = self.slots.index(a)
+                rows.append((i, a))
+                tok[i, 0] = a.req.out[-1]
+                tables[i] = a.table.padded(m)
+                lens[i] = a.pos
+                if a.state is not None:
+                    state_slots[i] = a.state.idx
+                adapter_ids[i] = a.req._adapter_slot
+            batch = {"token": jnp.asarray(tok),
+                     "block_tables": jnp.asarray(tables),
+                     "seq_lens": jnp.asarray(lens)}
+            if self.has_state:
+                batch["state_slots"] = jnp.asarray(state_slots)
+            lora = self._lora_descriptor(adapter_ids)
+            if lora is not None:
+                batch["lora"] = lora
+            # the batched dispatch has no single owner: a crash here blames
+            # no rid and _on_step_crash falls back to the youngest live
+            # request
+            if self.faults is not None:
+                self.faults.check("step")
+        with tracing.span("decode.dispatch") as sp:
+            if sp:
+                # each row attends over its written KV plus the new token
+                sp.facts["spans"] = [a.pos + 1 for _, a in rows]
+            self.cache, logits = self._decode_fn(self.params, self.cache,
+                                                 batch)
+        with tracing.span("decode.device_wait"):
+            logits.block_until_ready()
+        with tracing.span("decode.logits_copy"):
+            logits_np = np.asarray(logits)
         now = time.monotonic()
-        for i, a in rows:
-            req = a.req
-            with self._blame(req.rid):
-                nxt = self._sample(logits_np[i], req.sampling, len(req.out))
-                req.out.append(nxt)
-                a.pos += 1
-                self._decode_tokens += 1
-                self._tenant_count(req)
-                if req.on_token is not None:
-                    req.on_token(nxt, len(req.out) - 1)
-                if len(req.out) >= req.max_new or a.pos >= self.max_len:
-                    self._retire(a, now=now)
+        with tracing.span("decode.sample"):
+            picked = []
+            for i, a in rows:
+                with self._blame(a.req.rid):
+                    picked.append(self._sample(logits_np[i], a.req.sampling,
+                                               len(a.req.out)))
+        with tracing.span("decode.emit"):
+            for (_, a), nxt in zip(rows, picked):
+                req = a.req
+                with self._blame(req.rid):
+                    req.out.append(nxt)
+                    a.pos += 1
+                    self._decode_tokens += 1
+                    self._tenant_count(req)
+                    if req.on_token is not None:
+                        req.on_token(nxt, len(req.out) - 1)
+                    if len(req.out) >= req.max_new or a.pos >= self.max_len:
+                        self._retire(a, now=now)
         return True
 
     # -- engine loop -------------------------------------------------------
@@ -1386,10 +1447,15 @@ class ServeEngine:
         to do."""
         if self._t0 is None:
             self._t0 = time.monotonic()
-        worked = self._reap_deadlines() > 0
-        worked = self._admit() > 0 or worked
-        worked = self._prefill_step() or worked
-        worked = self._decode_step() or worked
+        with tracing.span("engine.step"):
+            with tracing.span("engine.reap"):
+                worked = self._reap_deadlines() > 0
+            with tracing.span("engine.admit"):
+                worked = self._admit() > 0 or worked
+            with tracing.span("engine.prefill"):
+                worked = self._prefill_step() or worked
+            with tracing.span("engine.decode"):
+                worked = self._decode_step() or worked
         if worked:
             self.steps += 1
             self._t_last = time.monotonic()
