@@ -293,43 +293,56 @@ def lm_decode_step_paged(cfg: ModelConfig, params, cache: Dict, batch: Dict):
     "seq_lens" (B,) int32}.  Every row sits at its own position — no shared
     ``cur_len`` — which is what makes continuous batching (rows at wildly
     different depths) exact instead of aligned-and-masked.
+
+    The whole KV pool rides the layer scan's carry: each ``(L, N, bs, KV,
+    hd)`` leaf is viewed as ``(L*N, bs, KV, hd)`` (merging the leading axes
+    leaves the tiled minor axes alone, so the view is a bitcast), and layer
+    ``l`` adds ``l*N`` to the block tables, so a table's null entry 0
+    becomes layer l's own null block.  Each layer scatters its new K/V into
+    the carried pool and reads it there: no layer's slab is sliced out and
+    stacked back, and with the pool donated the program updates it in
+    place.  The scan runs over super-layers; slot ``i`` of super-layer ``s``
+    is layer ``i * n_super + s``, the slot-major order ``lm_prefill``
+    stores.
     """
     seq_lens = batch["seq_lens"].astype(jnp.int32)
     tables = batch["block_tables"].astype(jnp.int32)
     x = embed_tokens(params["embed"], batch["token"])
-    every, k_slots, v_slots = _slot_major_split(cfg, cache)
+    every = cfg.moe.every if cfg.moe else 1
+    n_super = cfg.n_layers // every
+    shape = cache["k"].shape
+    n_blocks = shape[1]
+    flat = (shape[0] * n_blocks,) + shape[2:]
     # multi-LoRA: per-row adapter slot ids + stacked slabs ride the batch
     # only when the engine has adapters loaded — absent, not even a zero-add
     # is traced (the adapter_id=None bitwise-identity contract)
     lora = batch.get("lora")
     lora_ids = None if lora is None else lora["ids"].astype(jnp.int32)
-    lora_slots = lora_mod.split_layers(lora, every)
 
-    def body(x, xs):
-        lps, kcs, vcs, lsl = xs if lora is not None else (*xs, None)
-        new_kc, new_vc = [], []
+    def body(carry, xs):
+        x, k_pool, v_pool = carry
+        lps, s, lsl = xs if lora is not None else (*xs, None)
         for i, lp in enumerate(lps):
-            kc, vc = kcs[i], vcs[i]
             ll = None if lsl is None else {"ids": lora_ids,
                                            "slabs": lsl[i]}
             xn = rms_norm(x, lp["ln1"], cfg.norm_eps)
-            o, kc, vc = attn.attention_decode_block_paged(
-                cfg, lp["attn"], xn, kc, vc, tables, seq_lens, lora=ll)
+            o, k_pool, v_pool = attn.attention_decode_block_paged(
+                cfg, lp["attn"], xn, k_pool, v_pool,
+                tables + (i * n_super + s) * n_blocks, seq_lens, lora=ll)
             h = x + o
             y, _ = _ffn(cfg, lp, rms_norm(h, lp["ln2"], cfg.norm_eps),
                         decode=True, lora=ll)
             x = h + y
-            new_kc.append(kc)
-            new_vc.append(vc)
-        return x, (tuple(new_kc), tuple(new_vc))
+        return (x, k_pool, v_pool), None
 
-    xs = (params["layers"], k_slots, v_slots)
+    xs = (params["layers"], jnp.arange(n_super, dtype=jnp.int32))
     if lora is not None:
-        xs = xs + (lora_slots,)
-    x, (new_k, new_v) = jax.lax.scan(body, x, xs)
+        xs = xs + (lora_mod.split_layers(lora, every),)
+    (x, k_pool, v_pool), _ = jax.lax.scan(
+        body, (x, cache["k"].reshape(flat), cache["v"].reshape(flat)), xs)
     h = rms_norm(x, params["final_norm"], cfg.norm_eps)
     logits = logits_from_hidden(cfg, params["embed"], h)[:, 0, :]
-    return _slot_major_merge(new_k, new_v, every), logits
+    return {"k": k_pool.reshape(shape), "v": v_pool.reshape(shape)}, logits
 
 
 def lm_prefill_chunk(cfg: ModelConfig, params, cache: Dict, batch: Dict,

@@ -188,6 +188,15 @@ _REQUEST_SPANS = ("request.inbox", "request.queue", "request.prefill",
                   "request.decode")
 
 
+def _count_donation(pool) -> None:
+    """With tracing on, count whether a dispatch consumed the pool it was
+    given (``kv.pool_donated``) or left it live, which means the program
+    wrote a whole new pool (``kv.pool_copied``).  Host metadata only."""
+    if tracing.enabled():
+        donated = all(x.is_deleted() for x in jax.tree.leaves(pool))
+        tracing.count("kv.pool_donated" if donated else "kv.pool_copied")
+
+
 def _trace_request(req: Request) -> None:
     """A request's spans, a view of its stamps (received, submit,
     prefill0, first, done): each span between two stamps that were
@@ -549,13 +558,20 @@ class ServeEngine:
                 attn_lib.set_serve_mesh(None)
                 param_sharding.set_serve_tp(None)
 
-        self._decode_fn = jax.jit(_decode)
+        # decode consumes the pool it is given (donated) and hands back the
+        # updated one: its layer scan writes the pool in place, so a decode
+        # step moves no pool bytes (models/transformer.lm_decode_step_paged).
+        # Prefill still slices each layer's slab out of the scan's xs, where
+        # donation would make the program copy more, so it stays undonated
+        self._decode_fn = jax.jit(_decode, donate_argnums=(1,))
         # one retrace per distinct m_used (bounded by max_blocks_per_seq),
         # each strictly cheaper than the old full-table trace
         self._prefill_fn = jax.jit(_prefill, static_argnames=("m_used",))
 
     # the jitted fns thread the device slab functionally; the store's device
-    # tier holds the current reference between dispatches
+    # tier holds the current reference between dispatches.  A decode
+    # dispatch deletes the pool it was given, so every holder reads
+    # ``cache`` anew after one and keeps no reference across it.
     @property
     def cache(self):
         return self.store.device.cache
@@ -1332,8 +1348,10 @@ class ServeEngine:
             req.t_prefill0 = time.monotonic()
         with tracing.span("prefill.dispatch", rid=req.rid, start=start,
                           end=end, last=end >= plen):
-            self.cache, logits = self._prefill_fn(self.params, self.cache,
-                                                  batch, m_used=m_used)
+            pool = self.cache
+            self.cache, logits = self._prefill_fn(self.params, pool, batch,
+                                                  m_used=m_used)
+        _count_donation(pool)
         a.next_prefill = end
         self._prefill_tokens += end - start
         if a.prefill_done:
@@ -1413,8 +1431,9 @@ class ServeEngine:
             if sp:
                 # each row attends over its written KV plus the new token
                 sp.facts["spans"] = [a.pos + 1 for _, a in rows]
-            self.cache, logits = self._decode_fn(self.params, self.cache,
-                                                 batch)
+            pool = self.cache
+            self.cache, logits = self._decode_fn(self.params, pool, batch)
+        _count_donation(pool)
         with tracing.span("decode.device_wait"):
             logits.block_until_ready()
         with tracing.span("decode.logits_copy"):

@@ -67,6 +67,9 @@ class DeviceTier:
     (shape per leaf: ``(n_layers, num_blocks, block_size, n_kv, head_dim)``);
     the engine reads it for every dispatch and writes the updated pytree
     back, so the tier holds the *current* reference between dispatches.
+    A decode dispatch donates the pytree it is given, which deletes it:
+    every method here reads ``self.cache`` at the moment it acts, and no
+    caller may keep a reference across a dispatch.
     Data-plane ops (copy/read/write of one block) are injected by the model
     family so the tier never assumes a leaf layout.
 

@@ -7,6 +7,7 @@ import numpy as np
 import pytest
 
 from repro.configs.base import get_config, reduced_config
+from repro.models import attention as attn_lib
 from repro.models import build_model
 from repro.serve.engine import Request, SamplingParams, ServeEngine
 from repro.serve.paged_cache import dense_equiv_blocks
@@ -319,3 +320,27 @@ def test_engine_plans_paged_kernels_through_pipeline(setup):
     # cache hit on identical shapes: a second engine reuses the plan
     eng2 = ServeEngine(cfg, params, max_batch=2, max_len=32, block_size=8)
     assert eng2.compile_reports["decode"].cache_hit
+
+
+def test_each_program_enters_paged_attention_once(setup, monkeypatch):
+    """Lowering the decode program and one prefill program traces the
+    layer scan's body once: each attention dispatch is entered once per
+    program, so the layers are scanned, not unrolled, and nothing in the
+    program is traced twice."""
+    cfg, fns, params = setup
+    eng = ServeEngine(cfg, params, max_batch=2, max_len=32, block_size=4,
+                      plan_kernels=False)
+    entered = []
+    for name in ("_paged_decode_attend", "_paged_prefill_attend"):
+        def counted(*a, _fn=getattr(attn_lib, name), _name=name):
+            entered.append(_name)
+            return _fn(*a)
+        monkeypatch.setattr(attn_lib, name, counted)
+    m, i32 = eng.max_blocks_per_seq, (lambda *s: jnp.zeros(s, jnp.int32))
+    eng._decode_fn.lower(eng.params, eng.cache, {
+        "token": i32(2, 1), "block_tables": i32(2, m), "seq_lens": i32(2)})
+    assert entered == ["_paged_decode_attend"]
+    eng._prefill_fn.lower(eng.params, eng.cache, {
+        "tokens": i32(1, eng.prefill_chunk_tokens), "block_table": i32(1, m),
+        "start": i32(), "prompt_len": i32()}, m_used=m)
+    assert entered == ["_paged_decode_attend", "_paged_prefill_attend"]

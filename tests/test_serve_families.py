@@ -1,21 +1,26 @@
 """Cross-family serving oracle matrix: every model family in the zoo
-(transformer / ssm / hybrid) through the SAME paged ``ServeEngine``, each
-run token-identical to the family's dense ``prefill`` + ``decode_step``
+(transformer / moe / ssm / hybrid) through the SAME paged ``ServeEngine``,
+each run token-identical to the family's dense ``prefill`` + ``decode_step``
 reference — over greedy and sampled decoding, with chunked prefill on and
-off, and across a forced preemption-by-swap that parks recurrent state in
-the StateSlab's host tier mid-generation."""
+off, with adapter rows in the batch, on a serve mesh and through the
+interpreted Pallas kernel, and across a forced preemption-by-swap that
+parks recurrent state in the StateSlab's host tier mid-generation."""
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.configs.base import get_config, reduced_config
+from repro.launch.mesh import make_serve_mesh
 from repro.models import build_model
 from repro.serve.engine import Request, SamplingParams, ServeEngine
 from repro.serve.faults import check_kv_invariants
 
 FAMILY_ARCHS = {
     "transformer": "qwen3-0.6b",
+    "moe": "llama4-maverick-400b-a17b",      # a MoE layer every other layer
     "ssm": "falcon-mamba-7b",
     "hybrid": "zamba2-2.7b",
 }
@@ -29,6 +34,13 @@ def zoo():
     out = {}
     for family, arch in FAMILY_ARCHS.items():
         cfg = reduced_config(get_config(arch))
+        if family == "moe":
+            # two super-layers of (dense, MoE), so the pool's slot-major
+            # layer index (slot * n_super + super-layer) reaches past both
+            # slot 0 and super-layer 0; and room for every token at every
+            # expert, so a chunked prefill routes as the whole prompt does
+            cfg = dataclasses.replace(cfg, n_layers=4, moe=dataclasses.replace(
+                cfg.moe, capacity_factor=float(cfg.moe.n_experts)))
         assert cfg.family == ("dense" if family == "transformer" else family)
         fns = build_model(cfg)
         out[family] = (cfg, fns, fns.init(jax.random.PRNGKey(0)))
@@ -83,6 +95,18 @@ def _requests(cfg, sampled: bool):
     return reqs
 
 
+def _serve_matches_oracle(model, eng, reqs):
+    cfg, fns, params = model
+    for r in reqs:
+        eng.submit(r)
+    finished = eng.run_until_done()
+    assert len(finished) == len(reqs)
+    for r in reqs:
+        assert r.out == _oracle(cfg, fns, params, r), \
+            f"{cfg.name} rid={r.rid} diverged from its dense oracle"
+    check_kv_invariants(eng)
+
+
 @pytest.mark.parametrize("family", sorted(FAMILY_ARCHS))
 @pytest.mark.parametrize("sampled", [False, True], ids=["greedy", "sampled"])
 @pytest.mark.parametrize("chunked", [False, True],
@@ -97,25 +121,42 @@ def test_family_matches_dense_oracle(zoo, family, sampled, chunked):
     eng = ServeEngine(cfg, params, max_batch=2, max_len=MAX_LEN,
                       block_size=BLOCK_SIZE, plan_kernels=False,
                       prefill_chunk_tokens=4 if chunked else MAX_LEN)
-    reqs = _requests(cfg, sampled)
-    for r in reqs:
-        eng.submit(r)
-    finished = eng.run_until_done()
-    assert len(finished) == len(reqs)
-    for r in reqs:
-        assert r.out == _oracle(cfg, fns, params, r), \
-            f"{family} rid={r.rid} diverged from its dense oracle"
-    check_kv_invariants(eng)
+    _serve_matches_oracle(zoo[family], eng, _requests(cfg, sampled))
     # stateful families keep recurrent state in the slab, not the pool:
     # a drained engine holds zero slab slots and (for pure ssm) never
     # allocated a single KV block
-    if family == "transformer":
+    if family in ("transformer", "moe"):
         assert eng.state_store is None
     else:
         assert eng.state_store.device.pool.num_used == 0
         assert eng.state_store.device.pool.peak_used >= 1
         if family == "ssm":
             assert eng.pool.peak_used == 0
+
+
+@pytest.mark.parametrize("family", ["transformer", "moe"])
+@pytest.mark.parametrize("rows", ["adapter", "mesh", "kernel"])
+def test_pool_paths_match_dense_oracle(zoo, family, rows, monkeypatch):
+    """The other ways into the paged pool, greedy and chunked: a batch that
+    mixes base rows with the rows of a rank-0 adapter (its delta is exactly
+    zero, and its slabs ride the layer scan beside the pool), a 1-device
+    serve mesh (a NamedSharding pool, attention under shard_map), and the
+    Pallas kernel (interpreted here) reading the pool through each layer's
+    offset block tables."""
+    if rows == "kernel":
+        monkeypatch.setenv("REPRO_PAGED_ATTN", "kernel")
+    cfg = zoo[family][0]
+    eng = ServeEngine(cfg, zoo[family][2], max_batch=2, max_len=MAX_LEN,
+                      block_size=BLOCK_SIZE, plan_kernels=False,
+                      prefill_chunk_tokens=4,
+                      mesh=make_serve_mesh(1) if rows == "mesh" else False)
+    reqs = _requests(cfg, sampled=False)
+    if rows == "adapter":
+        eng.load_adapter("null-tenant", rank=0)
+        reqs[1].adapter_id = "null-tenant"
+    _serve_matches_oracle(zoo[family], eng, reqs)
+    if rows == "mesh":
+        assert eng.cache["k"].sharding.spec[-2] == "model"
 
 
 @pytest.mark.parametrize("family", ["ssm", "hybrid"])

@@ -12,6 +12,7 @@ The topology is described inside the fixture only, never at import, so
 each test worker collects the same tests and only the one that runs this
 file loads the TPU library.
 """
+import dataclasses
 import os
 
 import jax
@@ -21,7 +22,9 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.configs.base import get_config
 from repro.kernels.lora import lora_expand_kernel, lora_shrink_kernel
+from repro.kernels import ops
 from repro.kernels.paged_attention import paged_attention_kernel
+from repro.models import build_model
 
 CFG = get_config("qwen3-0.6b")
 KV, HD = CFG.n_kv_heads, CFG.resolved_head_dim
@@ -106,3 +109,41 @@ def test_lora_expand_compiles(one_chip):
                ((8, 16), jnp.float32),
                ((4, 16, CFG.q_dim), jnp.bfloat16), ((8,), jnp.int32))
     assert "tpu_custom_call" in hlo
+
+
+# the step programs: 4 of the model's layers over a pool of the size the
+# one-chip deployment's 8 rows would fill at 2048 tokens each
+POOL_PAGES, LAYERS, ROWS = 2049, 4, 8
+
+
+@pytest.mark.parametrize("path", ["gather", "kernel"])
+def test_decode_program_updates_the_donated_pool_in_place(one_chip, path,
+                                                          monkeypatch):
+    """The paged decode program, the pool donated as the engine donates
+    it: the compiler aliases the whole pool to the output and needs less
+    scratch than one layer's K slab, so no layer's slab is copied out of
+    the pool and back."""
+    monkeypatch.setenv("REPRO_PAGED_ATTN", path)
+    if path == "kernel":
+        # the wrappers would pick interpret mode from the CPU backend
+        monkeypatch.setattr(ops, "_interpret", lambda: False)
+    fns = build_model(dataclasses.replace(CFG, n_layers=LAYERS))
+
+    def shapes(tree):
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=one_chip), tree)
+
+    params = shapes(jax.eval_shape(fns.init, jax.random.PRNGKey(0)))
+    pool = shapes(jax.eval_shape(
+        lambda: fns.make_paged_cache(POOL_PAGES, BS)))
+    i32 = lambda *s: jnp.zeros(s, jnp.int32)
+    batch = shapes({"token": i32(ROWS, 1), "block_tables": i32(ROWS, M),
+                    "seq_lens": i32(ROWS)})
+    pool_bytes = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(pool))
+    slab_bytes = POOL_PAGES * BS * KV * HD * 2          # one layer's K, bf16
+    compiled = jax.jit(fns.decode_paged, donate_argnums=(1,)).lower(
+        params, pool, batch).compile()
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= pool_bytes
+    assert mem.temp_size_in_bytes < slab_bytes, mem
+    assert ("tpu_custom_call" in compiled.as_text()) == (path == "kernel")

@@ -2,7 +2,8 @@
 spans nested by thread with their request ids, a bounded ring, the TTFT
 split that adds up to the request's own stamps, dispatch facts equal to
 what the benchmark's recorder notes, profiler annotations on the same
-clock, and compile time counted once."""
+clock, compile time counted once, and each dispatch counted as having
+consumed the KV pool it was given or not."""
 import asyncio
 import glob
 import os
@@ -171,6 +172,31 @@ def test_dispatch_facts_equal_the_benchmark_recorder(setup, tracer):
             mine.append(("decode", 0, 0, False, r.facts["spans"]))
     assert len(theirs) > 10
     assert mine == theirs
+
+
+def test_decode_consumes_the_pool_it_is_given(setup, tracer):
+    """Decode donates the KV pool: after a decode dispatch the pool it was
+    handed is deleted and only the returned one is live.  Prefill does not
+    donate, so its input pool stays live.  The tracer counts each decode
+    dispatch as donated and each prefill dispatch as copied."""
+    cfg, params = setup
+    engine = _engine(cfg, params)
+    given = []
+    for name in ("_prefill_fn", "_decode_fn"):
+        def handed(p, c, b, _fn=getattr(engine, name), _name=name, **kw):
+            given.append((_name, c))
+            return _fn(p, c, b, **kw)
+        setattr(engine, name, handed)
+    _serve(engine, PROMPTS[:1], max_new=3)
+    deleted = {n: [all(x.is_deleted() for x in jax.tree.leaves(c))
+                   for m, c in given if m == n] for n, _ in given}
+    assert deleted == {"_prefill_fn": [False] * 3, "_decode_fn": [True] * 2}
+    assert not any(x.is_deleted() for x in jax.tree.leaves(engine.cache))
+    dispatches = Counter(r.name for r in tracer.drain()
+                         if r.name.endswith(".dispatch"))
+    assert dispatches == {"prefill.dispatch": 3, "decode.dispatch": 2}
+    assert tracer.counters().get("kv.pool_donated") == 2
+    assert tracer.counters().get("kv.pool_copied") == 3
 
 
 def test_annotations_agree_with_records_on_the_trace_clock(setup, tracer,
