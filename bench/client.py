@@ -6,6 +6,10 @@ with two changes: a request is timed from the moment it was due (so a
 stall counts against the requests queued behind it), and every token's id
 and arrival time are kept (the correctness check reads the ids, the
 latency metrics the times).
+
+Two ways to send a schedule: ``open_loop`` sends each request at its due
+time whatever is in flight; ``closed_loop`` keeps a fixed number of
+clients, each sending its next request once its previous answer ended.
 """
 from __future__ import annotations
 
@@ -36,9 +40,12 @@ class Record:
 
 
 def payload(model_id: str, ask: Ask) -> dict:
-    return {"model": model_id, "prompt": ask.prompt,
+    body = {"model": model_id, "prompt": ask.prompt,
             "max_tokens": ask.max_tokens, "stream": True,
-            "temperature": 0.0}
+            "temperature": ask.temperature}
+    if ask.temperature > 0:
+        body.update(top_k=ask.top_k, seed=ask.seed)
+    return body
 
 
 async def stream(port: int, body: dict, rec: Record) -> Record:
@@ -122,6 +129,29 @@ async def open_loop(port: int, model_id: str, sched: Schedule, t0: float,
         await stream(port, payload(model_id, rec.ask), rec)
 
     tasks = [asyncio.ensure_future(one(r)) for r in recs]
+    await asyncio.sleep(max(0.0, t0 + seconds - time.monotonic()))
+    await _finish(tasks, t0 + seconds + grace)
+    return recs
+
+
+async def closed_loop(port: int, model_id: str, sched: Schedule, t0: float,
+                      seconds: float, grace: float) -> List[Record]:
+    """``sched.clients`` clients take the asks in order, each sending its
+    next once its previous answer has ended, until the window closes at
+    ``t0 + seconds``; a request is due when it is sent.  Clients that find
+    the list used up stop."""
+    asks = iter(sched.asks)
+    recs: List[Record] = []
+
+    async def client():
+        for ask in asks:
+            if time.monotonic() >= t0 + seconds:
+                return
+            rec = Record(ask, time.monotonic())
+            recs.append(rec)
+            await stream(port, payload(model_id, ask), rec)
+
+    tasks = [asyncio.ensure_future(client()) for _ in range(sched.clients)]
     await asyncio.sleep(max(0.0, t0 + seconds - time.monotonic()))
     await _finish(tasks, t0 + seconds + grace)
     return recs

@@ -10,7 +10,9 @@ program's checks.  On the seeds in ``--control-seeds`` the float8
 control is also put in the program's place: the same reference with its
 linear layers in float8 e4m3, judged by the token it puts first at each
 position of the same prompts and served tokens, through the same
-comparison, so a sound limit makes it come out not correct.  The limit
+comparison, so a sound limit makes it come out not correct.  At a sampled
+request's positions any token among the reference's ``top_k`` best
+passes, so there only greedy requests can catch the control.  The limit
 lies between the largest program reading and the smallest control
 reading.  The benchmark's own runs never run the control.
 """

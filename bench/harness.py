@@ -7,22 +7,29 @@ The system under test is the OpenAI gateway booted in-process
 are the benchmark's, made on the device from the seed.  The engine is
 given the deployment facts of the configuration file (arch, max_batch,
 max_len, block_size) and nothing else, so its defaults run as shipped.
+Every family-specific fact (the program's config, the weights, the plain
+reference, FLOP and byte counts) comes from the module the configuration
+names under ``reference`` (``spec.load_reference``).
 """
 from __future__ import annotations
 
 import asyncio
 import dataclasses
+import functools
 import gc
 import os
 import shutil
 import sys
 import tempfile
 import time
-from typing import Callable, Dict, List, Optional
+from types import ModuleType
+from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 
-from bench import client, flops, measures, reference, spec, trace
+from bench import client, measures, spec, trace
 from bench.spans import SpanRecorder
 from bench.traffic.generate import longest_request, make_schedule
 
@@ -42,38 +49,31 @@ def log(*parts) -> None:
     print(*parts, file=sys.stderr, flush=True)
 
 
-def program_config(conf: Dict, strict: bool = True):
+def program_config(conf: Dict, reference: ModuleType, strict: bool = True):
     """The program's ModelConfig for a configuration file: the registry's
-    entry with the file's numbers.  ``strict`` requires the two to agree,
-    so a run at full size runs exactly the registered model."""
+    entry with the file's numbers, laid on by the configuration's
+    reference module, which refuses a family it does not cover.
+    ``strict`` requires the two to agree, so a run at full size runs
+    exactly the registered model."""
     from repro.configs.base import get_config
 
     base = get_config(conf["registry_id"])
-    m = flops.dims(conf)
-    cfg = dataclasses.replace(
-        base, n_layers=m.layers, d_model=m.d, n_heads=m.heads,
-        n_kv_heads=m.kv_heads, d_ff=m.ff, vocab=m.vocab, head_dim=m.head_dim,
-        rope_theta=float(conf["rope_theta"]),
-        norm_eps=float(conf["rms_norm_eps"]),
-        tie_embeddings=bool(conf["tie_word_embeddings"]),
-        qk_norm=bool(conf.get("qk_norm", False)), dtype=conf["torch_dtype"])
+    try:
+        cfg = reference.program_config(conf, base)
+    except ValueError as e:
+        raise SetupError(str(e)) from e
     if strict and cfg != base:
         diff = {f.name: (getattr(base, f.name), getattr(cfg, f.name))
                 for f in dataclasses.fields(cfg)
                 if getattr(base, f.name) != getattr(cfg, f.name)}
         raise SetupError(f"{conf['registry_id']}: the configuration file "
                          f"differs from the registry (registry, file): {diff}")
-    if cfg.family != "dense" or cfg.act != "swiglu" or cfg.rope != "rope":
-        raise SetupError(f"the reference covers dense SwiGLU RoPE decoders, "
-                         f"not {cfg.family}/{cfg.act}/{cfg.rope}")
     return cfg
 
 
 def check_layout(cfg, params) -> None:
     """The benchmark's weights must have the program's own structure,
     shapes and types (read from its init without running it)."""
-    import jax
-
     from repro.models import build_model
 
     want = jax.eval_shape(build_model(cfg).init, jax.random.PRNGKey(0))
@@ -87,8 +87,6 @@ class CompileCounter:
     """Programs compiled or loaded from the cache while ``active``."""
 
     def __init__(self):
-        import jax
-
         self.active = False
         self.count = 0
         jax.monitoring.register_event_listener(self._event)
@@ -106,7 +104,8 @@ class CompileCounter:
 @dataclasses.dataclass
 class Session:
     records: List[client.Record]
-    t0: float
+    t0: float                   # the window opens
+    t_ready: float              # the warm-up ended and the clients start
     trace_dir: Optional[str] = None
     host_window_ns: tuple = (0, 0)
     sync_ns: int = 0
@@ -128,11 +127,15 @@ async def _serve(router, sched, seconds: float, traced: bool,
         if bad:
             raise SetupError(f"warm-up request failed: status {bad[0].status} "
                              f"finish {bad[0].finish!r}")
-        t0 = time.monotonic()
+        # a closed loop's clients start now and fill the batch before the
+        # window opens; the ramp is serving, not set-up
+        t_ready = time.monotonic()
+        t0 = t_ready + sched.ramp_s
         counter.active = True
-        task = asyncio.ensure_future(client.open_loop(
-            gw.port, model_id, sched, t0, seconds, grace))
-        s = Session(records=[], t0=t0)
+        loop = client.closed_loop if sched.clients else client.open_loop
+        task = asyncio.ensure_future(loop(gw.port, model_id, sched, t0,
+                                          seconds, grace))
+        s = Session(records=[], t0=t0, t_ready=t_ready)
         if traced:
             await _trace_middle(s, t0, seconds)
         s.records = await task
@@ -140,14 +143,15 @@ async def _serve(router, sched, seconds: float, traced: bool,
         # end every stream before the server waits for its connections:
         # a request the engine never finishes would hold Gateway.stop
         await router.stop()
+    if sched.clients and len(s.records) == len(sched.asks):
+        raise SetupError(f"the closed loop used up all {len(sched.asks)} "
+                         f"requests of the mix before the window closed")
     return s
 
 
 async def _trace_middle(s: Session, t0: float, seconds: float) -> None:
     """Profile the middle of the window; the profiler starts and stops off
     the event loop so the clients keep their schedule."""
-    import jax
-
     loop = asyncio.get_running_loop()
     length = min(TRACE_MAX_S, seconds / 3)
     await asyncio.sleep(max(0.0, t0 + (seconds - length) / 2
@@ -195,33 +199,69 @@ def breakdown(t: measures.Traced) -> Dict:
 
 def pick_checked(records: List[client.Record], seed: int
                  ) -> List[client.Record]:
-    """Answered requests the reference reads: the longest one, then
-    others in an order drawn from the seed, up to CHECK_TOKENS served
-    tokens or CHECK_REQUESTS requests."""
-    pool = [r for r in records if r.ok]
-    if not pool:
-        return []
-    longest = max(pool, key=lambda r: (len(r.ask.prompt) + len(r.tokens),
-                                       r.ask.index))
-    rest = [r for r in pool if r is not longest]
-    order = np.random.default_rng([seed, 2]).permutation(len(rest))
-    out, n = [longest], len(longest.tokens)
-    for i in order:
-        if n >= CHECK_TOKENS or len(out) >= CHECK_REQUESTS:
-            break
-        out.append(rest[i])
-        n += len(rest[i].tokens)
+    """Answered requests the reference reads, greedy ones and sampled ones
+    apart (so each kind is read whatever its share): of each, the longest
+    one, then others in an order drawn from the seed, up to CHECK_TOKENS
+    served tokens or CHECK_REQUESTS requests."""
+    out: List[client.Record] = []
+    for kind, greedy in enumerate((True, False)):
+        pool = [r for r in records
+                if r.ok and (r.ask.temperature <= 0) == greedy]
+        if not pool:
+            continue
+        longest = max(pool, key=lambda r: (len(r.ask.prompt) + len(r.tokens),
+                                           r.ask.index))
+        rest = [r for r in pool if r is not longest]
+        order = np.random.default_rng([seed, 2 + kind]).permutation(
+            len(rest))
+        picked, n = [longest], len(longest.tokens)
+        for i in order:
+            if n >= CHECK_TOKENS or len(picked) >= CHECK_REQUESTS:
+                break
+            picked.append(rest[i])
+            n += len(rest[i].tokens)
+        out += picked
     return out
 
 
-def served_gaps(conf: Dict, params, picked: List[client.Record],
-                control: bool = False) -> List[np.ndarray]:
+@functools.partial(jax.jit, static_argnums=2)
+def kth_gap(ref_rows, picked, k: int):
+    """How far each picked token's reference logit lies below the
+    reference's k-th best logit of its row, 0 where it lies above; at
+    k = 1 the gap below the best."""
+    kth = jax.lax.top_k(ref_rows, k)[0][:, -1]
+    got = jnp.take_along_axis(ref_rows, picked[:, None], axis=-1)[:, 0]
+    return jnp.maximum(kth - got, 0.0)
+
+
+def served_rows(prompt: Sequence[int], served: Sequence[int]
+                ) -> Tuple[List[int], List[int]]:
+    """The sequence the reference reads and the rows whose logits chose
+    each served token: token i was chosen at position len(prompt)-1+i."""
+    seq = list(prompt) + list(served[:-1])
+    return seq, [len(prompt) - 1 + i for i in range(len(served))]
+
+
+def served_gaps(conf: Dict, reference: ModuleType, params,
+                picked: List[client.Record], control: bool = False
+                ) -> List[np.ndarray]:
     """Per checked request, each served token's gap below the reference's
-    best logit; with ``control``, the gap of the token the float8 control
-    puts first at the same position instead."""
+    k-th best logit (k = 1 for a greedy request, its ``top_k`` for a
+    sampled one); with ``control``, the gap of the token that the float8
+    control puts first at the same position instead."""
     pad = int(conf["deployment"]["max_len"])
-    gaps = reference.control_gaps if control else reference.served_gaps
-    return [gaps(conf, params, r.ask.prompt, r.tokens, pad) for r in picked]
+    out = []
+    for r in picked:
+        seq, rows = served_rows(r.ask.prompt, r.tokens)
+        ref = reference.logits_at(conf, params, seq, rows, pad)
+        if control:
+            ctrl = reference.logits_at(conf, params, seq, rows, pad,
+                                       quant="fp8")
+            chosen = jnp.argmax(ctrl, axis=-1).astype(jnp.int32)
+        else:
+            chosen = jnp.asarray(r.tokens, jnp.int32)
+        out.append(np.asarray(kth_gap(ref, chosen, r.ask.rank_allowed)))
+    return out
 
 
 def check(conf: Dict, records: List[client.Record],
@@ -240,8 +280,9 @@ def check(conf: Dict, records: List[client.Record],
 
 
 def gap_readings(gaps: List[np.ndarray]) -> Dict:
-    """The widest gap, the mean gap and the share of tokens off the
-    reference's best, over every checked token (control runs)."""
+    """The widest gap, the mean gap and the share of tokens below their
+    allowed rank (``off_best_share``), over every checked token (control
+    runs)."""
     g = np.concatenate(gaps) if gaps else np.zeros(0)
     if not g.size:
         return {}
@@ -260,12 +301,10 @@ def passed(checks: Dict) -> bool:
 def build(cell: spec.Cell, cfg, seed: int):
     """(weights, router): the benchmark's weights from ``seed`` and the
     gateway's router over one engine given only the deployment facts."""
-    import jax
-
     from repro.serve.gateway import Router, build_model
 
     dep = cell.config["deployment"]
-    params = reference.make_weights(cell.config, seed)
+    params = cell.reference.make_weights(cell.config, seed)
     jax.block_until_ready(params)
     check_layout(cfg, params)
     model = build_model(cfg, params, max_batch=int(dep["max_batch"]),
@@ -291,10 +330,8 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, traced: bool,
     program's place for ``checks`` and ``correct``, and keeps the
     program's own checks of the same run in ``program_checks``;
     ``grace`` is how long answers due in the window are awaited."""
-    import jax
-
     conf, dep = cell.config, cell.config["deployment"]
-    cfg = program_cfg or program_config(conf)
+    cfg = program_cfg or program_config(conf, cell.reference)
     if longest_request(cell.traffic) > int(dep["max_len"]):
         raise SetupError("the mix's longest request exceeds max_len")
     devices = devices or jax.devices()
@@ -312,8 +349,9 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, traced: bool,
 
     log(f"setup: engine ready at {time.monotonic() - t_start:.1f} s")
     s = asyncio.run(_serve(router, sched, seconds, traced, counter, grace))
-    setup_s = s.t0 - t_start
-    log(f"setup: window opened at {setup_s:.1f} s")
+    setup_s = s.t_ready - t_start
+    log(f"setup: clients started at {setup_s:.1f} s, window opened at "
+        f"{s.t0 - t_start:.1f} s")
     peak = max(int((d.memory_stats() or {}).get("peak_bytes_in_use", 0))
                for d in devices)
     # nothing of the program may outlive this point: the reference runs
@@ -328,7 +366,7 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, traced: bool,
     run = measures.Run(conf=conf, peaks=spec.peaks_for(devices[0].device_kind)
                        if devices[0].platform == "tpu" else {},
                        seconds=seconds, setup_s=setup_s, t0=s.t0,
-                       records=s.records)
+                       records=s.records, reference=cell.reference)
     out: Dict = {}
     if traced:
         try:
@@ -342,10 +380,11 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, traced: bool,
 
     t_check = time.monotonic()
     picked = pick_checked(s.records, seed)
-    gaps = served_gaps(conf, params, picked)
+    gaps = served_gaps(conf, cell.reference, params, picked)
     checks = check(conf, s.records, gaps)
     if control:
-        ctrl = served_gaps(conf, params, picked, control=True)
+        ctrl = served_gaps(conf, cell.reference, params, picked,
+                           control=True)
         out["program_checks"] = checks
         out["readings"] = {"program": gap_readings(gaps),
                            "control": gap_readings(ctrl)}
@@ -388,8 +427,6 @@ def use_caches() -> None:
     (the program's own default, ``repro.launch.compile_cache``), every
     program however quick to compile, and the e-graph kernel plan in
     ``.jax_cache/egraph``."""
-    import jax
-
     os.environ["REPRO_CACHE_DIR"] = str(spec.ROOT / ".jax_cache" / "egraph")
     jax.config.update("jax_compilation_cache_dir",
                       str(spec.ROOT / ".jax_cache"))

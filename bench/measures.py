@@ -9,6 +9,7 @@ from __future__ import annotations
 
 import dataclasses
 import statistics
+from types import ModuleType
 from typing import Dict, List, Optional, Sequence
 
 import numpy as np
@@ -39,6 +40,7 @@ class Run:
     setup_s: float
     t0: float                          # window start, monotonic s
     records: list                      # bench.client.Record
+    reference: ModuleType              # the config's: FLOP and byte counts
     traced: Optional[Traced] = None
 
 
@@ -109,10 +111,10 @@ def decode_device_ms(run: Run) -> Optional[float]:
 
 
 def _step_flops(run: Run, d: Dispatch) -> int:
-    m = flops.dims(run.conf)
+    ref = run.reference
     if d.kind == "decode":
-        return flops.decode_step_flops(m, d.spans)
-    return flops.prefill_chunk_flops(m, d.start, d.end, d.last)
+        return ref.decode_step_flops(run.conf, d.spans)
+    return ref.prefill_chunk_flops(run.conf, d.start, d.end, d.last)
 
 
 def step_mfu(run: Run) -> Optional[float]:
@@ -152,12 +154,12 @@ def paged_decode_roofline(run: Run) -> Optional[tuple]:
     ex, ds = _execs(run, DECODE), _dispatched(run, "decode")
     if not kernel or not ex or not ds:
         return None
-    m = flops.dims(run.conf)
+    ref = run.reference
     need, bounds = [], []
     for d in ds:
         t, bound = flops.least_seconds(
-            flops.paged_decode_attention_flops(m, d.spans),
-            flops.paged_decode_attention_bytes(m, d.spans), run.peaks)
+            ref.paged_decode_attention_flops(run.conf, d.spans),
+            ref.paged_decode_attention_bytes(run.conf, d.spans), run.peaks)
         need.append(t)
         bounds.append(bound)
     kernel_s = sum(e.dur_ns for e in kernel) * 1e-9

@@ -1,5 +1,15 @@
-"""The benchmark's weights and its plain float32 reference of the served
-models, independent of the program's model code.
+"""The reference module of dense SwiGLU RoPE decoders (Qwen3, Phi-3): the
+benchmark's weights, its plain float32 reference of the served model,
+independent of the program's model code, and the model's FLOP and byte
+counts.  A configuration names it under ``reference``; the harness and the
+metric readers take every family-specific fact from it:
+
+    program_config(conf, base)      the program's ModelConfig, or ValueError
+                                    for a family this module does not cover
+    weight_layout, make_weights     the weights, from the seed
+    logits_at(..., quant=None)      the reference's logits; "fp8" the control
+    decode_step_flops, prefill_chunk_flops,
+    paged_decode_attention_flops, paged_decode_attention_bytes
 
 ``make_weights`` draws a configuration's weights from the seed on the
 device, in one jitted call, in bfloat16 (the type they are served in),
@@ -23,14 +33,16 @@ column of the weights), accumulated in float32; attention stays float32.
 """
 from __future__ import annotations
 
+import dataclasses
 import functools
 import math
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Dict, Iterable, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from bench import flops
 from bench.flops import Dims, dims
 
 HIGHEST = jax.lax.Precision.HIGHEST
@@ -44,6 +56,22 @@ def jax_key(seed: int):
     s = int(seed) % 2**64
     key = jax.random.key(np.uint32(s & 0xFFFFFFFF))
     return jax.random.fold_in(key, np.uint32(s >> 32))
+
+
+def program_config(conf: Dict, base):
+    """The registry's ModelConfig ``base`` with the file's numbers laid
+    onto it; a family this reference does not compute is refused."""
+    if base.family != "dense" or base.act != "swiglu" or base.rope != "rope":
+        raise ValueError(f"the dense reference covers dense SwiGLU RoPE "
+                         f"decoders, not {base.family}/{base.act}/{base.rope}")
+    m = dims(conf)
+    return dataclasses.replace(
+        base, n_layers=m.layers, d_model=m.d, n_heads=m.heads,
+        n_kv_heads=m.kv_heads, d_ff=m.ff, vocab=m.vocab, head_dim=m.head_dim,
+        rope_theta=float(conf["rope_theta"]),
+        norm_eps=float(conf["rms_norm_eps"]),
+        tie_embeddings=bool(conf["tie_word_embeddings"]),
+        qk_norm=bool(conf.get("qk_norm", False)), dtype=conf["torch_dtype"])
 
 
 def weight_layout(conf: Dict) -> Dict:
@@ -221,35 +249,21 @@ def logits_at(conf: Dict, params, tokens: Sequence[int], rows: Sequence[int],
     return logits[:len(rows)]
 
 
-@jax.jit
-def _gap(ref_rows, picked):
-    """How far below the reference's best logit each picked token lies."""
-    best = jnp.max(ref_rows, axis=-1)
-    got = jnp.take_along_axis(ref_rows, picked[:, None], axis=-1)[:, 0]
-    return best - got
+# ---------------------------------------------------------------------------
+# FLOPs and bytes (bench/flops.py), as the metric readers ask for them
+# ---------------------------------------------------------------------------
+
+def decode_step_flops(conf: Dict, spans: Iterable[int]) -> int:
+    return flops.decode_step_flops(dims(conf), spans)
 
 
-def served_rows(prompt: Sequence[int], served: Sequence[int]
-                ) -> Tuple[List[int], List[int]]:
-    """The sequence the reference reads and the rows whose logits chose
-    each served token: token i was chosen at position len(prompt)-1+i."""
-    seq = list(prompt) + list(served[:-1])
-    return seq, [len(prompt) - 1 + i for i in range(len(served))]
+def prefill_chunk_flops(conf: Dict, start: int, end: int, last: bool) -> int:
+    return flops.prefill_chunk_flops(dims(conf), start, end, last)
 
 
-def served_gaps(conf: Dict, params, prompt, served, pad_to: int
-                ) -> np.ndarray:
-    """Each served token's gap below the reference's best logit."""
-    seq, rows = served_rows(prompt, served)
-    ref = logits_at(conf, params, seq, rows, pad_to)
-    return np.asarray(_gap(ref, jnp.asarray(served, jnp.int32)))
+def paged_decode_attention_flops(conf: Dict, spans: Iterable[int]) -> int:
+    return flops.paged_decode_attention_flops(dims(conf), spans)
 
 
-def control_gaps(conf: Dict, params, prompt, served, pad_to: int
-                 ) -> np.ndarray:
-    """At the same positions, the gap of the token that the fp8 control
-    puts first."""
-    seq, rows = served_rows(prompt, served)
-    ref = logits_at(conf, params, seq, rows, pad_to)
-    ctrl = logits_at(conf, params, seq, rows, pad_to, quant="fp8")
-    return np.asarray(_gap(ref, jnp.argmax(ctrl, axis=-1).astype(jnp.int32)))
+def paged_decode_attention_bytes(conf: Dict, spans: Iterable[int]) -> int:
+    return flops.paged_decode_attention_bytes(dims(conf), spans)

@@ -5,6 +5,10 @@ Everything that belongs to one configuration, one mix or one metric is a
 file of its own, found by name:
 
   bench/configs/<config>.json   (the ``file`` of the config's entry)
+  <its "reference">             the module of everything family-specific:
+                                ``program_config``, the weights, the plain
+                                reference's logits, the FLOP and byte counts
+                                (``bench/reference.py`` for dense decoders)
   bench/traffic/<traffic>.json  parameters read by ``bench.traffic.generate``
   bench/metrics/<metric>.py     a reader ``read(run) -> float | None``
   bench/peaks.json              per ``device_kind``
@@ -15,9 +19,11 @@ is an error, never a default.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import importlib.util
 import json
 from pathlib import Path
+from types import ModuleType
 from typing import Callable, Dict, List
 
 ROOT = Path(__file__).resolve().parents[1]
@@ -29,6 +35,7 @@ class Cell:
     chips: int
     config_name: str
     config: Dict            # the configuration file, as run
+    reference: ModuleType   # the module the configuration names
     traffic_name: str
     traffic: Dict           # the traffic file
     end_to_end: List[Dict]  # BENCHMARK.json entries this cell reports
@@ -76,10 +83,19 @@ def load_cell(name: str, root: Path = ROOT) -> Cell:
     if not traffic_path.is_file():
         raise FileNotFoundError(f"no traffic file {traffic_path}")
     return Cell(name=name, chips=int(w["chips"]), config_name=c["name"],
-                config=config, traffic_name=w["traffic"],
+                config=config, reference=load_reference(config, root),
+                traffic_name=w["traffic"],
                 traffic=json.loads(traffic_path.read_text()),
                 end_to_end=end_to_end_for(bench, name),
                 per_layer=per_layer_for(bench, name))
+
+
+def _exec_file(path: Path, prefix: str, name: str) -> ModuleType:
+    mod_name = prefix + "".join(ch if ch.isalnum() else "_" for ch in name)
+    spec = importlib.util.spec_from_file_location(mod_name, path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
 
 
 def load_reader(metric: str, root: Path = ROOT) -> Callable:
@@ -87,12 +103,25 @@ def load_reader(metric: str, root: Path = ROOT) -> Callable:
     path = Path(root) / "bench" / "metrics" / f"{metric}.py"
     if not path.is_file():
         raise FileNotFoundError(f"no reader for metric {metric!r} at {path}")
-    mod_name = "bench_metric_" + "".join(
-        ch if ch.isalnum() else "_" for ch in metric)
-    spec = importlib.util.spec_from_file_location(mod_name, path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return _exec_file(path, "bench_metric_", metric).read
+
+
+@functools.lru_cache(maxsize=None)
+def _reference_at(path: Path) -> ModuleType:
+    # one module object per file, so its compiled programs are reused
+    return _exec_file(path, "bench_reference_", str(path))
+
+
+def load_reference(conf: Dict, root: Path = ROOT) -> ModuleType:
+    """The module a configuration names under ``reference``, a path
+    relative to the root."""
+    if "reference" not in conf:
+        raise KeyError(f"configuration {conf.get('registry_id')!r} names no "
+                       f"reference module")
+    path = (Path(root) / conf["reference"]).resolve()
+    if not path.is_file():
+        raise FileNotFoundError(f"no reference module at {path}")
+    return _reference_at(path)
 
 
 def peaks_for(device_kind: str, root: Path = ROOT) -> Dict:

@@ -51,7 +51,8 @@ async def _sweep(router, cell, seed: int, seconds: float, rates):
             recs = await client.open_loop(gw.port, model_id, sched, t0,
                                           seconds, 60.0)
             run = measures.Run(conf=cell.config, peaks={}, seconds=seconds,
-                               setup_s=0.0, t0=t0, records=recs)
+                               setup_s=0.0, t0=t0, records=recs,
+                               reference=cell.reference)
             row = {"rate_per_s": rate, "requests": len(recs),
                    "failed": sum(not r.ok for r in recs),
                    "tokens_per_s": measures.window_tokens(run) / seconds,
@@ -86,8 +87,8 @@ def main(argv=None) -> int:
     require_chips(cell.chips)
     harness.use_caches()
     rates = sorted(float(r) for r in args.rates.split(","))
-    _, router = harness.build(cell, harness.program_config(cell.config),
-                              args.seed)
+    _, router = harness.build(
+        cell, harness.program_config(cell.config, cell.reference), args.seed)
     rows = asyncio.run(_sweep(router, cell, args.seed, args.seconds, rates))
     knee = None
     for r in rows:              # ascending: the knee is below the first miss

@@ -39,7 +39,20 @@ def test_run_refuses_a_host_without_a_tpu():
 
 @pytest.mark.parametrize("cell", CELLS)
 def test_traffic_is_the_same_work_for_every_seed(cell):
-    mix = spec.load_cell(cell).traffic
+    _same_work_for_every_seed(spec.load_cell(cell).traffic)
+
+
+def test_a_closed_loop_sampled_mix_is_the_same_work_for_every_seed():
+    # the first cell's lengths, sent by 16 closed-loop clients, a quarter of
+    # the requests greedy and the rest sampled from the 50 likeliest tokens
+    mix = dict(spec.load_cell(CELLS[0]).traffic,
+               arrival={"kind": "closed", "clients": 16, "requests": 600},
+               sampling={"temperature": 0.8, "top_k": 50,
+                         "greedy_share": 0.25})
+    _same_work_for_every_seed(mix)
+
+
+def _same_work_for_every_seed(mix):
     vocab = 1000
     a = generate.make_schedule(mix, 5, 30.0, vocab)
     b = generate.make_schedule(mix, 5, 30.0, vocab)
@@ -58,8 +71,22 @@ def test_traffic_is_the_same_work_for_every_seed(cell):
         assert p["min"] <= len(x.prompt) <= p["max"]
         assert o["min"] <= x.max_tokens <= o["max"]
         assert all(1 <= t < vocab for t in x.prompt)
-    rate = mix["arrival"]["rate_per_s"]
-    assert len(a.asks) == round(rate * 30.0)
+    # the same requests are greedy, and sample alike, on every seed; only
+    # the sampling seeds differ
+    settings = lambda s: [(x.temperature, x.top_k) for x in s.asks]  # noqa
+    assert settings(a) == settings(c)
+    sampled = [i for i, x in enumerate(a.asks) if x.temperature > 0]
+    assert all(a.asks[i].seed != c.asks[i].seed for i in sampled)
+    assert all(x.seed == 0 for x in a.asks if x.temperature == 0)
+    share = mix.get("sampling", {}).get("greedy_share", 1.0)
+    assert len(a.asks) - len(sampled) == round(share * len(a.asks))
+    arr = mix["arrival"]
+    if arr["kind"] == "closed":
+        assert len(a.asks) == arr["requests"]
+        assert a.clients == arr["clients"] and a.ramp_s == generate.RAMP_S
+        return
+    rate = arr["rate_per_s"]
+    assert len(a.asks) == round(rate * 30.0) and a.clients == 0
     due = [x.due_s for x in a.asks]
     assert due == sorted(due) and due[-1] < 30.0
     assert sorted(np.diff([0.0] + due)) == pytest.approx(
@@ -79,6 +106,10 @@ def test_lengths_are_stratified_quantiles():
 @pytest.mark.parametrize("change", [
     {"sampling": {"temperature": 0.7}},
     {"arrival": {"kind": "closed", "clients": 16}},
+    # a sampled token bounded by no top_k could not be checked
+    {"sampling": {"temperature": 0.7, "top_k": 0, "greedy_share": 0.25}},
+    {"arrival": {"kind": "burst", "rate_per_s": 2.0}},
+    {"sharing": {"prefix_tokens": 512}},
 ])
 def test_a_mix_the_generator_cannot_serve_is_refused(change):
     mix = dict(spec.load_cell(CELLS[0]).traffic, **change)
@@ -87,9 +118,16 @@ def test_a_mix_the_generator_cannot_serve_is_refused(change):
 
 
 def test_cells_configs_mixes_and_metrics_found_by_name():
+    sys.path.insert(0, str(ROOT / "src"))
+    from bench import harness
+
     for name in CELLS:
         cell = spec.load_cell(name)
         assert cell.config["deployment"]["chips"] == cell.chips
+        # the file's numbers are the registered model's, as its reference
+        # module lays them on
+        cfg = harness.program_config(cell.config, cell.reference)
+        assert cfg.name == cell.config["registry_id"]
         for m in cell.end_to_end + cell.per_layer:
             assert callable(spec.load_reader(m["name"]))
     with pytest.raises(KeyError):

@@ -78,7 +78,8 @@ def test_measures_on_recorded_trace():
     lo, hi = raw["window_ns"]
     conf = json.loads((ROOT / "bench/configs/qwen3-0.6b.json").read_text())
     run = measures.Run(conf=conf, peaks=spec.peaks_for("TPU v5 lite"),
-                       seconds=1.0, setup_s=0.0, t0=0.0, records=[])
+                       seconds=1.0, setup_s=0.0, t0=0.0, records=[],
+                       reference=spec.load_reference(conf))
     run.traced = measures.Traced(
         events=events, window_ns=(lo, hi), host_window_ns=(lo, hi),
         spans=spans, requests=[],

@@ -31,7 +31,7 @@ def _run(traced=True):
                         host_window_ns=(10_000, 11_000), spans=[],
                         dispatches=[], requests=[]) if traced else None
     return measures.Run(conf={}, peaks={}, seconds=2.0, setup_s=0.0, t0=1.0,
-                        records=[], traced=t)
+                        records=[], reference=None, traced=t)
 
 
 def _requests():
